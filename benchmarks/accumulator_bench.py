@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.sparse import random as sprand
 from repro.core import binning, csr, predictor, spgemm
 from repro.core.flop import flop_per_row
@@ -170,6 +171,7 @@ def main(argv=None) -> int:
     p.add_argument("--quick", action="store_true",
                    help="CI-sized matrices (rows/4)")
     args = p.parse_args(argv)
+    compile_cache.configure()
     reset_records()
     run(quick=args.quick)
     out = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",

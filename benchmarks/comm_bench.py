@@ -32,6 +32,7 @@ import numpy as np
 from repro.sparse import random as sprand
 from repro.sparse.formats import CSR
 from repro.core import oracle
+from repro import compile_cache
 from repro.core import plan as plan_mod
 
 try:
@@ -39,6 +40,7 @@ try:
 except ImportError:   # invoked as a script: python benchmarks/comm_bench.py
     from common import timeit, emit, reset_records, write_bench_json
 
+SHARDS = 4
 _LAST: dict = {}
 
 
@@ -63,12 +65,13 @@ def _revalue(m: CSR, seed: int) -> CSR:
 
 def run(quick: bool = False):
     _LAST.clear()
-    shards = min(4, len(jax.devices()))
-    if shards < 2:
+    if len(jax.devices()) < SHARDS:
         raise SystemExit(
-            "comm_bench needs a multi-device host mesh; line 23 only "
-            "DEFAULTS XLA_FLAGS — unset it or include "
-            "--xla_force_host_platform_device_count=4 in it")
+            f"comm_bench needs {SHARDS} devices, found {len(jax.devices())}"
+            "; on CPU the XLA_FLAGS default above gives four unless "
+            "XLA_FLAGS was already set without "
+            "--xla_force_host_platform_device_count=4")
+    shards = SHARDS
     mesh = jax.make_mesh((shards,), ("data",))
     for fam, a, b in _cases(quick):
         rec = {}
@@ -139,6 +142,7 @@ def main(argv=None) -> int:
     p.add_argument("--quick", action="store_true",
                    help="CI-sized matrices (rows/4)")
     args = p.parse_args(argv)
+    compile_cache.configure()
     reset_records()
     run(quick=args.quick)
     out = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
@@ -147,7 +151,7 @@ def main(argv=None) -> int:
     print(json.dumps(summary(), indent=1))
     print(f"wrote {out}")
     ok = True
-    npan = min(4, len(jax.devices()))
+    npan = SHARDS
     for fam, rec in summary().items():
         for k, s in rec.items():
             if k == "replicated":

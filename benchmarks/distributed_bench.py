@@ -31,6 +31,7 @@ import numpy as np
 from repro.sparse import random as sprand
 from repro.sparse.formats import CSR
 from repro.core import oracle
+from repro import compile_cache
 from repro.core import plan as plan_mod
 
 try:
@@ -40,6 +41,7 @@ except ImportError:   # invoked as a script: python benchmarks/distributed_bench
     from common import timeit, emit, reset_records, write_bench_json
     import legacy_distributed as distributed
 
+SHARDS = 4
 _LAST: dict = {}
 
 
@@ -66,7 +68,10 @@ def _revalue(m: CSR, seed: int) -> CSR:
 
 def run(quick: bool = False):
     _LAST.clear()
-    shards = min(4, len(jax.devices()))
+    if len(jax.devices()) < SHARDS:
+        raise SystemExit(f"distributed_bench needs {SHARDS} devices, found "
+                         f"{len(jax.devices())}")
+    shards = SHARDS
     mesh = jax.make_mesh((shards,), ("data",))
     for fam, a, b in _cases(quick):
         # -- legacy global-pad shard path -------------------------------- #
@@ -132,6 +137,7 @@ def main(argv=None) -> int:
     p.add_argument("--quick", action="store_true",
                    help="CI-sized matrices (rows/4)")
     args = p.parse_args(argv)
+    compile_cache.configure()
     reset_records()
     run(quick=args.quick)
     out = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.sparse.formats import CSR
 from repro.core import csr as csr_mod
@@ -97,10 +96,10 @@ def distributed_spgemm(a: CSR, b: CSR, mesh, plan: DistSpGEMMPlan, *,
         return (oc[None], ov[None], nnz[None], ofl[None])
 
     spec_in = P(axis, None)
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(spec_in,),
-                   out_specs=(P(axis, None, None), P(axis, None, None),
-                              P(axis, None), P(axis)),
-                   check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(spec_in,),
+                       out_specs=(P(axis, None, None), P(axis, None, None),
+                                  P(axis, None), P(axis)),
+                       check_vma=False)
     oc, ov, nnz, ofl = jax.jit(fn)(rows)
     return oc, ov, nnz, ofl
 

@@ -33,6 +33,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.sparse import random as sprand
 from repro.sparse.formats import CSR
 from repro.core import plan as plan_mod
@@ -215,6 +216,7 @@ def main(argv=None) -> int:
     p.add_argument("--quick", action="store_true",
                    help="CI-sized matrices")
     args = p.parse_args(argv)
+    compile_cache.configure()
     reset_records()
     run(quick=args.quick)
     out = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",

@@ -38,6 +38,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import binning, profiles
 from repro.serve import admission
 from repro.sparse import random as sprand
@@ -145,6 +146,7 @@ def main(argv=None) -> int:
     p.add_argument("--quick", action="store_true",
                    help="4-cell CI grid instead of the 10-cell full grid")
     args = p.parse_args(argv)
+    compile_cache.configure()
     reset_records()
     run(quick=args.quick)
     out = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",

@@ -8,34 +8,43 @@ Usage: PYTHONPATH=src python -m benchmarks.run
 from __future__ import annotations
 
 import os
-import subprocess
 import sys
 import traceback
 
-from . import common
+# before JAX starts: four CPU devices for the 4-device sections (the flag
+# only affects the CPU backend; on a chip host JAX sees its own devices)
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+
+from . import common  # noqa: E402  (after the device-count flag)
 
 
-def _subprocess_bench(name: str):
-    """Benches needing the 4-device env var BEFORE jax init run as
-    subprocesses (each writes its own BENCH_*.json)."""
-    script = os.path.join(os.path.dirname(__file__), name)
+def _four_device_section(mod):
+    """A 4-device bench run in this process — the process that holds the
+    chips — writing its own BENCH_*.json, or reported as not run."""
 
     def run() -> None:
-        res = subprocess.run([sys.executable, script], check=False)
-        if res.returncode:
-            raise RuntimeError(f"{name} exited {res.returncode}")
+        import jax
+        n = len(jax.devices())
+        if n < mod.SHARDS:
+            print(f"not run: {mod.__name__} needs {mod.SHARDS} devices and "
+                  f"this process has {n}")
+            return
+        kept = list(common.RECORDS)    # its records go to its own artifact
+        try:
+            rc = mod.main([])
+        finally:
+            common.RECORDS[:] = kept
+        if rc:
+            raise RuntimeError(f"{mod.__name__} failed its gates (exit {rc})")
 
     return run
-
-
-_distributed_subprocess = _subprocess_bench("distributed_bench.py")
-_comm_subprocess = _subprocess_bench("comm_bench.py")
 
 
 def main() -> None:
     from . import (table2_suite, table3_accuracy, fig2_overhead,
                    kernels_bench, binning_bench, accumulator_bench,
-                   roofline_bench, moe_capacity_bench, partition_bench)
+                   roofline_bench, moe_capacity_bench, partition_bench,
+                   distributed_bench, comm_bench)
     sections = [
         ("table2 (suite stats)", table2_suite.run),
         ("table3 (625-case accuracy)", table3_accuracy.run),
@@ -46,9 +55,13 @@ def main() -> None:
         ("roofline (dry-run cells)", roofline_bench.run),
         ("moe capacity (beyond-paper)", moe_capacity_bench.run),
         ("partition (load balance)", partition_bench.run),
-        ("distributed (plan/execute vs legacy)", _distributed_subprocess),
-        ("comm (panel-gathered B vs replicated)", _comm_subprocess),
+        ("distributed (plan/execute vs legacy)",
+         _four_device_section(distributed_bench)),
+        ("comm (panel-gathered B vs replicated)",
+         _four_device_section(comm_bench)),
     ]
+    from repro import compile_cache
+    compile_cache.configure()
     common.reset_records()
     failed = 0
     for name, fn in sections:

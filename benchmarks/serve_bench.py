@@ -36,6 +36,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import faults, plan as plan_mod
 from repro.serve.spgemm_service import (RequestState, ServiceConfig,
                                         SpgemmService)
@@ -281,6 +282,7 @@ def main(argv=None) -> int:
     p.add_argument("--quick", action="store_true",
                    help="CI-sized matrices + request counts")
     args = p.parse_args(argv)
+    compile_cache.configure()
     reset_records()
     run(quick=args.quick)
     out = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",

@@ -56,7 +56,8 @@ class PlanMismatchError(SpgemmError):
     """An operand, mesh or template does not match the plan it is used
     with: wrong shape/capacity at ``to_device``, a panel-plan operand whose
     structure fingerprint differs from the planned one, a mesh whose axis
-    size differs from the planned shard count, or a template misuse."""
+    size differs from the planned shard count, a template misuse, or
+    ``use_kernel=True`` on a backend that cannot compile the kernels."""
 
 
 class CapacityExhaustedError(SpgemmError):

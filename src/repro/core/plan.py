@@ -72,7 +72,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.sparse.formats import CSR
 from . import binning as binning_mod
@@ -1057,7 +1056,14 @@ def plan_spgemm(a: CSR, b: CSR, *, mesh=None, num_shards: int | None = None,
     (row shard ``d // n_panels``, panel ``d % n_panels``) and receives ONLY
     the gathered panel entries its rows reference, replacing full B
     replication (``num_shards`` must be a multiple of ``n_panels``).
+
+    ``use_kernel`` runs every bucket through the Pallas kernels; on a TPU
+    backend it raises :class:`PlanMismatchError` before any dispatch, since
+    that compiler refuses them (``kernels.ops.require_compilable``).
     """
+    if use_kernel:
+        from repro.kernels import ops as kernel_ops
+        kernel_ops.require_compilable()
     operands_validated = 0
     if validate:
         validate_mod.validate_pair(a, b)
@@ -1343,11 +1349,11 @@ def _build_bucket_dist_executor(meta: tuple, mesh, axis: str,
         c, v, n, _ = _run_bucket(ad, bd, table[0], meta, use_kernel)
         return c[None], v[None], n.astype(jnp.int32)[None]
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(), P(), P(axis, None)),
-                   out_specs=(P(axis, None, None), P(axis, None, None),
-                              P(axis, None)),
-                   check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(), P(), P(axis, None)),
+                       out_specs=(P(axis, None, None), P(axis, None, None),
+                                  P(axis, None)),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -1372,8 +1378,8 @@ def _build_dist_executor(metas: tuple, mesh, axis: str, use_kernel: bool,
     out_specs = tuple(s for _ in range(nb)
                       for s in (P(axis, None, None), P(axis, None, None),
                                 P(axis, None)))
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -1441,8 +1447,8 @@ def _build_panel_dist_executor(metas: tuple, shape_a, nref: int, ncols_b: int,
     out_specs = tuple(s for _ in range(nb)
                       for s in (P(axis, None, None), P(axis, None, None),
                                 P(axis, None)))
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -2130,6 +2136,33 @@ def _replan_dist_panels(plan: SpgemmPlan, ad, g_val_host: np.ndarray,
     return DistSpgemmOut(tuple(cols), tuple(vals), out.row_nnz, overflow)
 
 
+def _local_executor(plan: SpgemmPlan, cache: PlanCache):
+    """The cached single-device executor of a replicated-B local plan."""
+    metas = tuple(_bucket_meta(bk, cap)
+                  for bk, cap in zip(plan.binning.buckets,
+                                     plan.alloc.bucket_capacities))
+    return cache.executor(
+        _executor_key(plan, None),
+        lambda: _build_local_executor(metas, plan.alloc.row_capacity,
+                                      plan.use_kernel, cache,
+                                      masked=plan.pop_quant))
+
+
+def lower_local(plan: SpgemmPlan, a, b, *,
+                cache: PlanCache | None = None):
+    """Lower, without running, the executor :func:`execute` dispatches for
+    a single-device replicated-B plan — ``.compile().memory_analysis()``
+    then gives its device bytes.  After an :func:`execute` of the same plan
+    the lowering and compile are served from JAX's in-memory caches."""
+    if plan.distributed or plan.n_panels or not plan.binning.buckets:
+        raise PlanMismatchError(
+            "lower_local needs a single-device replicated-B plan with at "
+            "least one bucket", plan_key=_plan_key_id(plan))
+    cache = cache if cache is not None else _DEFAULT_CACHE
+    ad, bd = _coerce_pair(plan, a, b)
+    return _local_executor(plan, cache).lower(ad, bd, *plan.device_args())
+
+
 def execute(plan: SpgemmPlan, a, b, *, mesh=None, cache: PlanCache | None = None):
     """Run the planned numeric phase.
 
@@ -2204,14 +2237,7 @@ def execute(plan: SpgemmPlan, a, b, *, mesh=None, cache: PlanCache | None = None
             if plan.retry_policy is not None or plan.retry_safety > 0:
                 out = _replan_local_panels(plan, ad, bps, out, cache)
             return out
-        metas = tuple(_bucket_meta(bk, cap)
-                      for bk, cap in zip(plan.binning.buckets,
-                                         plan.alloc.bucket_capacities))
-        run = cache.executor(
-            _executor_key(plan, None),
-            lambda: _build_local_executor(metas, plan.alloc.row_capacity,
-                                          plan.use_kernel, cache,
-                                          masked=plan.pop_quant))
+        run = _local_executor(plan, cache)
         try:
             out = _invoke_executor(run, dict(unit="local"),
                                    ad, bd, *plan.device_args(), **wave_kw)

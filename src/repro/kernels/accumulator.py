@@ -223,7 +223,7 @@ def _symbolic_call(kernel, outs, a_rpt, a_col, b_rpt, b_col, rows, *,
 def bitmask_symbolic_pallas(a_rpt, a_col, b_rpt, b_col, rows, *,
                             max_deg_a: int, max_deg_b: int, ncols_b: int,
                             span: int = 0, block_samples: int = 8,
-                            interpret: bool = True, rownnz_b=None):
+                            interpret: bool, rownnz_b=None):
     """(z*, f*) via bitmask popcount — bit-equal to the sort kernel.
 
     ``span`` is the planner's bound on per-row product-column extent
@@ -243,7 +243,7 @@ def fused_flop_symbolic_bitmask_pallas(a_rpt, a_col, b_rpt, b_col, rows, *,
                                        max_deg_a: int, max_deg_b: int,
                                        ncols_b: int, span: int = 0,
                                        block_samples: int = 8,
-                                       interpret: bool = True, rownnz_b=None):
+                                       interpret: bool, rownnz_b=None):
     """One pallas_call → (z*, f*, flop-per-sampled-row) — the SPA route of
     the binned predictor's fused per-bucket invocation."""
     s = rows.shape[0]
@@ -334,7 +334,7 @@ def _bin_numeric_kernel(rows_ref, a_rpt_ref, a_col_ref, a_val_ref, b_rpt_ref,
 def spa_numeric_pallas(a_rpt, a_col, a_val, b_rpt, b_col, b_val, rows, *,
                        max_deg_a: int, max_deg_b: int, ncols_b: int,
                        tile_n: int, n_tiles: int = 0, block_rows: int = 8,
-                       interpret: bool = True, rownnz_b=None):
+                       interpret: bool, rownnz_b=None):
     """Dense accumulator + presence + per-row column offsets for ``rows``:
     ``(acc, present, lo)`` with ``acc``/``present`` of shape
     ``(R, n_tiles·tile_n)`` covering each row's product-column extent
@@ -387,7 +387,7 @@ def spa_numeric_pallas(a_rpt, a_col, a_val, b_rpt, b_col, b_val, rows, *,
 def bin_numeric_pallas(a_rpt, a_col, a_val, b_rpt, b_col, b_val, rows, *,
                        max_deg_a: int, max_deg_b: int, ncols_b: int,
                        tile_n: int, n_tiles: int = 0, block_rows: int = 8,
-                       interpret: bool = True, rownnz_b=None):
+                       interpret: bool, rownnz_b=None):
     """Propagation-blocking numeric accumulate: ``(acc, present, lo)`` with
     the SAME output contract as :func:`spa_numeric_pallas` — ``(R,
     n_tiles·tile_n)`` extent-relative dense window + per-row offsets — but a

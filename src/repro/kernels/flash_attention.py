@@ -22,13 +22,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _compiler_params():
-    """The TPU compiler-params class was renamed across JAX releases
-    (CompilerParams ↔ TPUCompilerParams); resolve whichever exists."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=("parallel", "arbitrary"))
-
-
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                   sm_scale: float, causal: bool, block_q: int, block_k: int):
     qi = pl.program_id(0)
@@ -103,20 +96,19 @@ def _flash_single(q, k, v, *, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
+                    block_k: int = 128, interpret: bool):
     """Batched GQA flash attention.
 
     q: (batch, q_heads, sq, d); k, v: (batch, kv_heads, sk, d) with
     q_heads % kv_heads == 0.  Returns (batch, q_heads, sq, d).
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
     assert hq % hkv == 0

@@ -22,8 +22,8 @@ def _kernel(rpt_ref, col_ref, rownnz_b_ref, out_ref, *, block_rows: int,
             max_deg_a: int):
     i = pl.program_id(0)
     row0 = i * block_rows
-    starts = pl.load(rpt_ref, (pl.dslice(row0, block_rows),))
-    ends = pl.load(rpt_ref, (pl.dslice(row0 + 1, block_rows),))
+    starts = rpt_ref[pl.ds(row0, block_rows)]
+    ends = rpt_ref[pl.ds(row0 + 1, block_rows)]
     deg = ends - starts                                         # (BR,)
     ia = jax.lax.broadcasted_iota(jnp.int32, (block_rows, max_deg_a), 1)
     idx = starts[:, None] + ia                                  # (BR, DA)
@@ -38,7 +38,7 @@ def _kernel(rpt_ref, col_ref, rownnz_b_ref, out_ref, *, block_rows: int,
 @functools.partial(jax.jit, static_argnames=("block_rows", "max_deg_a", "interpret"))
 def flop_per_row_pallas(rpt: jax.Array, col: jax.Array, rownnz_b: jax.Array,
                         *, block_rows: int = 256, max_deg_a: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """floprC for all M rows.  ``rpt`` int32 (M+1,), ``col`` int32 (cap,)."""
     m = rpt.shape[0] - 1
     nblocks = -(-m // block_rows)
@@ -79,7 +79,7 @@ def _rows_kernel(rows_ref, rpt_ref, col_ref, rownnz_b_ref, out_ref, *,
 @functools.partial(jax.jit, static_argnames=("block_rows", "max_deg_a", "interpret"))
 def flop_rows_pallas(rpt: jax.Array, col: jax.Array, rownnz_b: jax.Array,
                      rows: jax.Array, *, block_rows: int = 256,
-                     max_deg_a: int = 128, interpret: bool = True) -> jax.Array:
+                     max_deg_a: int = 128, interpret: bool) -> jax.Array:
     """floprC for the listed ``rows`` only — the binned-pipeline variant,
     sized by the bucket's degree bound instead of the global one."""
     r = rows.shape[0]

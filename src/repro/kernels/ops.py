@@ -1,7 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True``; on TPU they lower
-natively.  Every wrapper has an identically-shaped oracle in ``ref.py``.
+On CPU the kernels run with ``interpret=True``; elsewhere they lower
+natively — except on TPU, whose compiler refuses them today
+(:func:`require_compilable`).  Every wrapper has an identically-shaped
+oracle in ``ref.py``.
 """
 from __future__ import annotations
 
@@ -19,8 +21,32 @@ from . import accumulator as _acc_k
 from . import flash_attention as _fa_k
 
 
+# Kernel entry points the TPU compiler refuses, each pinned with its refusal
+# by tests/test_tpu_compile.py: in-kernel gathers index whole-array
+# (``memory_space=pl.ANY``) refs with traced integers, and rank-1 row blocks
+# of 8 are not a multiple of the 128-lane tiling.
+TPU_REFUSED_KERNELS = (
+    "flop_per_row_pallas", "flop_rows_pallas", "sampled_symbolic_pallas",
+    "fused_flop_symbolic_pallas", "bitmask_symbolic_pallas",
+    "fused_flop_symbolic_bitmask_pallas", "spgemm_numeric_pallas",
+    "spa_numeric_pallas", "bin_numeric_pallas")
+
+
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
+
+
+def require_compilable() -> None:
+    """Refuse ``use_kernel=True`` up front on a backend whose compiler
+    rejects the kernels — a typed error before any dispatch, instead of a
+    Mosaic failure inside an executor that recovery would absorb."""
+    if jax.default_backend() == "tpu":
+        from repro.core.errors import PlanMismatchError
+        raise PlanMismatchError(
+            "use_kernel=True: the TPU compiler refuses the Pallas kernels "
+            f"{', '.join(TPU_REFUSED_KERNELS)}; serve with use_kernel=False "
+            "(the XLA path)", kernels=list(TPU_REFUSED_KERNELS),
+            backend="tpu")
 
 
 def _check_route(route: str) -> None:

@@ -58,7 +58,7 @@ def _kernel(rows_ref, a_rpt_ref, a_col_ref, a_val_ref, b_rpt_ref, b_col_ref,
     "max_deg_a", "max_deg_b", "block_rows", "interpret"))
 def spgemm_numeric_pallas(a_rpt, a_col, a_val, b_rpt, b_col, b_val, rows, *,
                           max_deg_a: int, max_deg_b: int, block_rows: int = 8,
-                          interpret: bool = True, rownnz_b=None):
+                          interpret: bool, rownnz_b=None):
     """Sorted/run-summed products for ``rows``.
 
     Returns (sorted_cols (R, F2), run_sums_at_first (R, F2), first_mask (R, F2)).

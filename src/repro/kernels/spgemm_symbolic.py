@@ -101,7 +101,7 @@ def _fused_kernel(rows_ref, a_rpt_ref, a_col_ref, b_rpt_ref, b_col_ref,
     "max_deg_a", "max_deg_b", "block_samples", "interpret"))
 def fused_flop_symbolic_pallas(a_rpt, a_col, b_rpt, b_col, rows, *,
                                max_deg_a: int, max_deg_b: int,
-                               block_samples: int = 8, interpret: bool = True):
+                               block_samples: int = 8, interpret: bool):
     """One pallas_call → (z*, f*, flop-per-sampled-row (S,)).
 
     The binned predictor issues this once per bucket: the sampled symbolic
@@ -141,7 +141,7 @@ def fused_flop_symbolic_pallas(a_rpt, a_col, b_rpt, b_col, rows, *,
     "max_deg_a", "max_deg_b", "block_samples", "interpret"))
 def sampled_symbolic_pallas(a_rpt, a_col, b_rpt, b_col, rows, *,
                             max_deg_a: int, max_deg_b: int,
-                            block_samples: int = 8, interpret: bool = True):
+                            block_samples: int = 8, interpret: bool):
     """Returns (z*, f*) — exact sampled NNZ and sampled FLOP (int32 scalars)."""
     s = rows.shape[0]
     nblocks = -(-s // block_samples)

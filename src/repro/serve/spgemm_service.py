@@ -242,6 +242,11 @@ class ServiceConfig:
     escalated_policy: plan_mod.RetryPolicy = plan_mod.RetryPolicy(
         rounds=2, growth=2.0, exact_fallback=True, on_exhausted="raise")
 
+    def __post_init__(self):
+        if self.use_kernel:
+            from repro.kernels import ops as kernel_ops
+            kernel_ops.require_compilable()
+
 
 class SpgemmService:
     """The scheduler.  Owns its own :class:`~repro.core.plan.PlanCache` and

@@ -229,3 +229,39 @@ def test_execute_rejects_mismatched_mesh():
     mesh = jax.make_mesh((1,), ("data",))      # single-device test env
     with pytest.raises(ValueError, match="4 shards"):
         plan_mod.execute(p, a, a, mesh=mesh)
+
+
+# --------------------------------------------------------------------------- #
+# backends: use_kernel on a TPU is refused, typed, before any dispatch
+# --------------------------------------------------------------------------- #
+def test_use_kernel_refused_on_tpu_before_dispatch(monkeypatch):
+    from repro.core.errors import PlanMismatchError, ShardFailureError
+    from repro.kernels import ops
+    from repro.serve.spgemm_service import ServiceConfig
+    a = sprand.erdos_renyi(64, 64, 3, seed=5)
+    cache = plan_mod.plan_cache()
+    before = cache.stats()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(PlanMismatchError) as ei:
+        plan_mod.plan_spgemm(a, a, use_kernel=True)
+    assert not isinstance(ei.value, ShardFailureError)
+    assert ei.value.context["kernels"] == list(ops.TPU_REFUSED_KERNELS)
+    with pytest.raises(PlanMismatchError):
+        ServiceConfig(use_kernel=True)
+    assert cache.stats() == before          # nothing was built or run
+    ServiceConfig()                         # the XLA path stays available
+
+
+def test_lower_local_reuses_the_executed_executor():
+    a = sprand.power_law(300, 300, 4, 1.6, seed=8)
+    cache = plan_mod.PlanCache()
+    plan = plan_mod.plan_spgemm(a, a, pop_quant=True)
+    plan_mod.execute(plan, a, a, cache=cache)
+    traces = cache.traces
+    ma = plan_mod.lower_local(plan, a, a, cache=cache).compile() \
+        .memory_analysis()
+    assert ma.output_size_in_bytes > 0
+    assert cache.traces == traces           # served from the plan cache
+    dist = plan_mod.plan_spgemm(a, a, num_shards=2)
+    with pytest.raises(plan_mod.PlanMismatchError):
+        plan_mod.lower_local(dist, a, a)
