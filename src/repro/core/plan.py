@@ -73,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.sparse.formats import CSR
 from . import binning as binning_mod
 from . import csr as csr_mod
@@ -1080,37 +1081,42 @@ def plan_spgemm(a: CSR, b: CSR, *, mesh=None, num_shards: int | None = None,
                                    growth=float(retry_safety),
                                    exact_fallback=False,
                                    on_exhausted="surface")
-    if isinstance(template, str):
-        if template != "auto":
-            raise PlanMismatchError(f"unknown template mode {template!r}")
-        reg = registry if registry is not None else _DEFAULT_REGISTRY
-        template = reg.get_or_create(a, b, lambda: PlanTemplate.from_plan(
-            plan_spgemm(a, b, seed=seed, safety=safety, route=route,
-                        use_kernel=use_kernel, sample_rows=sample_rows,
-                        min_rows=min_rows, pop_quant=True)))
-    if n_panels and (mesh is not None or num_shards):
-        shards_chk = int(num_shards if num_shards else mesh.shape[axis])
-        if shards_chk % int(n_panels):
-            raise PlanMismatchError(
-                f"n_panels={n_panels} must divide the mesh axis size "
-                f"{shards_chk} (panels fold onto the data axis)",
-                observed=int(shards_chk), planned=int(n_panels))
-    if template is not None:
-        pop_quant = True
-        template.grow_device_caps(a.nnz, b.nnz)
-        binplan = template.fit(a, b)
-    else:
-        if pop_quant and deg_align <= 1:
-            # quantized plans need quantized degree bounds, or the per-bucket
-            # signatures (exact degree maxima) would fragment the key anyway
-            deg_align = binning_mod.POW2_DEG_ALIGN
-        binplan = binning_mod.build_plan(a, b, route=route, min_rows=min_rows,
-                                         deg_align=deg_align)
-    flopr, total_flop = oracle.flop_per_row(a, b)
-    if sample_rows is None:
-        sample_rows = (oracle.sample_rows(a.nrows, seed) if a.nrows
-                       else np.zeros(0, dtype=np.int64))
-    sample_rows = np.asarray(sample_rows, dtype=np.int64)
+    with obs.span("plan.template"):
+        if isinstance(template, str):
+            if template != "auto":
+                raise PlanMismatchError(
+                    f"unknown template mode {template!r}")
+            reg = registry if registry is not None else _DEFAULT_REGISTRY
+            template = reg.get_or_create(a, b, lambda: PlanTemplate.from_plan(
+                plan_spgemm(a, b, seed=seed, safety=safety, route=route,
+                            use_kernel=use_kernel, sample_rows=sample_rows,
+                            min_rows=min_rows, pop_quant=True)))
+        if n_panels and (mesh is not None or num_shards):
+            shards_chk = int(num_shards if num_shards else mesh.shape[axis])
+            if shards_chk % int(n_panels):
+                raise PlanMismatchError(
+                    f"n_panels={n_panels} must divide the mesh axis size "
+                    f"{shards_chk} (panels fold onto the data axis)",
+                    observed=int(shards_chk), planned=int(n_panels))
+        if template is not None:
+            pop_quant = True
+            template.grow_device_caps(a.nnz, b.nnz)
+            binplan = template.fit(a, b)
+        else:
+            if pop_quant and deg_align <= 1:
+                # quantized plans need quantized degree bounds, or the
+                # per-bucket signatures (exact degree maxima) would
+                # fragment the key anyway
+                deg_align = binning_mod.POW2_DEG_ALIGN
+            binplan = binning_mod.build_plan(a, b, route=route,
+                                             min_rows=min_rows,
+                                             deg_align=deg_align)
+    with obs.span("plan.flop"):
+        flopr, total_flop = oracle.flop_per_row(a, b)
+        if sample_rows is None:
+            sample_rows = (oracle.sample_rows(a.nrows, seed) if a.nrows
+                           else np.zeros(0, dtype=np.int64))
+        sample_rows = np.asarray(sample_rows, dtype=np.int64)
 
     if template is not None:
         cap_a, cap_b = template.cap_a, template.cap_b
@@ -1119,15 +1125,18 @@ def plan_spgemm(a: CSR, b: CSR, *, mesh=None, num_shards: int | None = None,
         cap_b = _device_capacity(b.nnz)
     devpair = None
     if total_flop > 0 and sample_rows.size:
-        ad = csr_mod.to_device(a, capacity=cap_a)
-        bd = csr_mod.to_device(b, capacity=cap_b)
+        with obs.span("plan.upload"):
+            ad = csr_mod.to_device(a, capacity=cap_a)
+            bd = csr_mod.to_device(b, capacity=cap_b)
         devpair = (ad, bd)
-        pred = predictor_mod.proposed_predict_binned(
-            ad, bd, jnp.asarray(sample_rows, dtype=jnp.int32), binplan,
-            use_kernel=use_kernel, floprc=flopr)
-        structure = np.asarray(pred.structure, dtype=np.float64)
-        predicted_nnz = float(pred.nnz_total)
-        cr = float(pred.compression_ratio)
+        with obs.span("plan.predict"):
+            pred = predictor_mod.proposed_predict_binned(
+                ad, bd, jnp.asarray(sample_rows, dtype=jnp.int32), binplan,
+                use_kernel=use_kernel, floprc=flopr)
+            with obs.span("wait"):      # the first host read of the outputs
+                structure = np.asarray(pred.structure, dtype=np.float64)
+            predicted_nnz = float(pred.nnz_total)
+            cr = float(pred.compression_ratio)
         if not np.isfinite(structure).all() or cr <= 0:
             # sampled rows had no products (f* = 0): fall back to the
             # upper-bound structure — always safe, never over-allocates
@@ -1144,18 +1153,19 @@ def plan_spgemm(a: CSR, b: CSR, *, mesh=None, num_shards: int | None = None,
         predicted_nnz = 0.0
         cr = 1.0
 
-    alloc = predictor_mod.BinnedAllocationPlan.from_prediction(
-        binplan, structure, flopr, safety=safety, pow2=pop_quant)
-    if template is not None:
-        # the family's grown capacities dominate the member's prediction
-        template.grow_caps(alloc.bucket_capacities)
-        caps = tuple(template.caps)
-        alloc = predictor_mod.BinnedAllocationPlan(
-            bucket_capacities=caps,
-            row_capacity=max(caps) if caps else 8,
-            total_capacity=sum(bk.n_rows * c
-                               for bk, c in zip(binplan.buckets, caps)),
-            safety=safety)
+    with obs.span("plan.alloc"):
+        alloc = predictor_mod.BinnedAllocationPlan.from_prediction(
+            binplan, structure, flopr, safety=safety, pow2=pop_quant)
+        if template is not None:
+            # the family's grown capacities dominate the member's prediction
+            template.grow_caps(alloc.bucket_capacities)
+            caps = tuple(template.caps)
+            alloc = predictor_mod.BinnedAllocationPlan(
+                bucket_capacities=caps,
+                row_capacity=max(caps) if caps else 8,
+                total_capacity=sum(bk.n_rows * c
+                                   for bk, c in zip(binplan.buckets, caps)),
+                safety=safety)
 
     plan = SpgemmPlan(
         binning=binplan, alloc=alloc, structure=structure, flopr=flopr,
@@ -1282,6 +1292,17 @@ def _bucket_meta(bucket: binning_mod.RowBucket, cap: int) -> tuple:
             bucket.tile_n, bucket.n_tiles, bucket.span, int(cap))
 
 
+def _scope(i: int | None, meta: tuple, panel: int | None = None) -> str:
+    """Name scope of one bucket's pass inside an executor, so each device
+    op's ``tf_op`` in a profile names bucket and route: ``b3.esc``,
+    ``b3.p1.spa`` (bucket 3, panel 1), ``unit.esc`` (a one-bucket retry or
+    recovery executor, which serves every bucket of that shape)."""
+    where = "unit" if i is None else f"b{i}"
+    if panel is not None:
+        where += f".p{panel}"
+    return f"{where}.{meta[3]}"
+
+
 def _run_bucket(ad: CSRDevice, bd: CSRDevice, rows: jax.Array, meta: tuple,
                 use_kernel: bool) -> SpGEMMOut:
     deg_a, deg_b, block_rows, route, tile_n, n_tiles, span, cap = meta
@@ -1311,11 +1332,13 @@ def _build_local_executor(metas: tuple, cap_out: int, use_kernel: bool,
         tables = rest[nb:] if masked else rest
         parts_c, parts_v, parts_n = [], [], []
         overflow = jnp.int32(0)
-        for meta, rows, mask in zip(metas, tables, masks):
-            c, v, n, of = _run_bucket(ad, bd, rows, meta, use_kernel)
-            if masked:
-                of = jnp.where(mask, jnp.maximum(n - meta[-1], 0), 0).sum()
-            c, v = pad_to_capacity(c, v, cap_out)
+        for i, (meta, rows, mask) in enumerate(zip(metas, tables, masks)):
+            with jax.named_scope(_scope(i, meta)):
+                c, v, n, of = _run_bucket(ad, bd, rows, meta, use_kernel)
+                if masked:
+                    of = jnp.where(mask, jnp.maximum(n - meta[-1], 0),
+                                   0).sum()
+                c, v = pad_to_capacity(c, v, cap_out)
             parts_c.append(c)
             parts_v.append(v)
             parts_n.append(n.astype(jnp.int32))
@@ -1335,7 +1358,8 @@ def _build_bucket_executor(meta: tuple, use_kernel: bool, cache: PlanCache):
     @jax.jit
     def run(ad, bd, rows):
         cache._note_trace()
-        return _run_bucket(ad, bd, rows, meta, use_kernel)
+        with jax.named_scope(_scope(None, meta)):
+            return _run_bucket(ad, bd, rows, meta, use_kernel)
 
     return run
 
@@ -1346,7 +1370,8 @@ def _build_bucket_dist_executor(meta: tuple, mesh, axis: str,
 
     def shard_fn(ad, bd, table):
         cache._note_trace()
-        c, v, n, _ = _run_bucket(ad, bd, table[0], meta, use_kernel)
+        with jax.named_scope(_scope(None, meta)):
+            c, v, n, _ = _run_bucket(ad, bd, table[0], meta, use_kernel)
         return c[None], v[None], n.astype(jnp.int32)[None]
 
     fn = jax.shard_map(shard_fn, mesh=mesh,
@@ -1368,8 +1393,9 @@ def _build_dist_executor(metas: tuple, mesh, axis: str, use_kernel: bool,
     def shard_fn(ad, bd, *tables):
         cache._note_trace()
         outs = []
-        for meta, table in zip(metas, tables):
-            c, v, n, _ = _run_bucket(ad, bd, table[0], meta, use_kernel)
+        for i, (meta, table) in enumerate(zip(metas, tables)):
+            with jax.named_scope(_scope(i, meta)):
+                c, v, n, _ = _run_bucket(ad, bd, table[0], meta, use_kernel)
             outs.extend([c[None], v[None], n.astype(jnp.int32)[None]])
         return tuple(outs)
 
@@ -1398,12 +1424,14 @@ def _build_local_panel_executor(metas: tuple, use_kernel: bool,
         tables = rest[nb:] if masked else rest
         cols, vals, nnzs = [], [], []
         overflow = jnp.int32(0)
-        for pmetas, rows, mask in zip(metas, tables, masks):
+        for i, (pmetas, rows, mask) in enumerate(zip(metas, tables, masks)):
             bc, bv, bn = [], [], []
-            for bp, meta in zip(bps, pmetas):
-                c, v, n, of = _run_bucket(ad, bp, rows, meta, use_kernel)
-                if masked:
-                    of = jnp.where(mask, jnp.maximum(n - meta[-1], 0), 0).sum()
+            for p, (bp, meta) in enumerate(zip(bps, pmetas)):
+                with jax.named_scope(_scope(i, meta, p)):
+                    c, v, n, of = _run_bucket(ad, bp, rows, meta, use_kernel)
+                    if masked:
+                        of = jnp.where(mask, jnp.maximum(n - meta[-1], 0),
+                                       0).sum()
                 bc.append(c)
                 bv.append(v)
                 bn.append(n.astype(jnp.int32))
@@ -1436,8 +1464,9 @@ def _build_panel_dist_executor(metas: tuple, shape_a, nref: int, ncols_b: int,
         bd = CSRDevice(rpt=g_rpt[0], col=g_col[0], val=g_val[0],
                        shape=(nref, ncols_b))
         outs = []
-        for meta, table in zip(metas, tables):
-            c, v, n, _ = _run_bucket(ad, bd, table[0], meta, use_kernel)
+        for i, (meta, table) in enumerate(zip(metas, tables)):
+            with jax.named_scope(_scope(i, meta)):
+                c, v, n, _ = _run_bucket(ad, bd, table[0], meta, use_kernel)
             outs.extend([c[None], v[None], n.astype(jnp.int32)[None]])
         return tuple(outs)
 
@@ -1657,12 +1686,22 @@ def _invoke_executor(run, info: dict, *args, budget: "DispatchBudget | None"
         raise ShardFailureError(f"executor failed: {e}", **info) from e
 
 
+def _to_host(x) -> np.ndarray:
+    """``x`` as a host array; copying a device array counts its bytes as
+    ``d2h_bytes`` of the innermost open span (:mod:`repro.obs`)."""
+    h = np.asarray(x)
+    if isinstance(x, jax.Array):
+        obs.count("d2h_bytes", h.nbytes)
+    return h
+
+
 def _replan_local(plan: SpgemmPlan, ad, bd, out: SpGEMMOut,
                   cache: PlanCache) -> SpGEMMOut:
     policy = _policy_of(plan)
     buckets = plan.binning.buckets
     caps = list(plan.alloc.bucket_capacities)
-    n = np.asarray(out.row_nnz, dtype=np.int64)
+    with obs.span("execute.wait"):     # the first host read of the outputs
+        n = _to_host(out.row_nnz).astype(np.int64)
     col = val = None                   # materialized on first splice only
     args = plan.device_args()
     tables = args[1 + len(buckets):] if plan.pop_quant else args[1:]
@@ -1673,8 +1712,8 @@ def _replan_local(plan: SpgemmPlan, ad, bd, out: SpGEMMOut,
     def splice(i, new_cap, c2, v2):
         nonlocal col, val
         bk = buckets[i]
-        c2 = np.asarray(c2)[:bk.n_rows]
-        v2 = np.asarray(v2)[:bk.n_rows]
+        c2 = _to_host(c2)[:bk.n_rows]
+        v2 = _to_host(v2)[:bk.n_rows]
         if new_cap > col.shape[1]:
             grow = new_cap - col.shape[1]
             col = np.concatenate(
@@ -1694,9 +1733,12 @@ def _replan_local(plan: SpgemmPlan, ad, bd, out: SpGEMMOut,
              plan.cap_b, plan.use_kernel, meta, pop),
             lambda m=meta: _build_bucket_executor(m, plan.use_kernel,
                                                  cache))
-        c2, v2, _, _ = _invoke_executor(run, dict(unit=unit, bucket=i),
-                                        ad, bd, tables[i])
-        splice(i, new_cap, c2, v2)
+        with obs.span("execute.rerun"):
+            obs.count("reruns")
+            c2, v2, _, _ = _invoke_executor(run, dict(unit=unit, bucket=i),
+                                            ad, bd, tables[i])
+            obs.count("out_slots", c2.size)
+            splice(i, new_cap, c2, v2)
 
     for attempt in range(1, policy.rounds + 1):
         bumps = []
@@ -1714,8 +1756,8 @@ def _replan_local(plan: SpgemmPlan, ad, bd, out: SpGEMMOut,
         if not bumps:
             break
         if col is None:
-            col = np.asarray(out.col).copy()
-            val = np.asarray(out.val).copy()
+            col = _to_host(out.col).copy()
+            val = _to_host(out.val).copy()
         plan.retries = attempt
         for i, need, new_cap in bumps:
             rerun(i, new_cap, "bucket-retry")
@@ -1730,8 +1772,8 @@ def _replan_local(plan: SpgemmPlan, ad, bd, out: SpGEMMOut,
             if bk.n_rows and int(n[bk.rows].max()) > caps[i]]
     if over and policy.exact_fallback:
         if col is None:
-            col = np.asarray(out.col).copy()
-            val = np.asarray(out.val).copy()
+            col = _to_host(out.col).copy()
+            val = _to_host(out.val).copy()
         for i in over:
             bk = buckets[i]
             counts = predictor_mod.exact_row_counts(
@@ -1797,8 +1839,11 @@ def _replan_dist(plan: SpgemmPlan, ad, bd, out: DistSpgemmOut,
              _mesh_key(mesh)),
             lambda m=meta: _build_bucket_dist_executor(
                 m, mesh, plan.axis, plan.use_kernel, cache))
-        c2, v2, _ = _invoke_executor(run, dict(unit=unit, bucket=i),
-                                     ad, bd, args[i])
+        with obs.span("execute.rerun"):
+            obs.count("reruns")
+            c2, v2, _ = _invoke_executor(run, dict(unit=unit, bucket=i),
+                                         ad, bd, args[i])
+            obs.count("out_slots", c2.size)
         cols[i], vals[i] = c2, v2
         tables[i] = dataclasses.replace(t, capacity=new_cap)
 
@@ -1879,8 +1924,9 @@ def _replan_local_panels(plan: SpgemmPlan, ad, bps, out: PanelSpgemmOut,
     buckets = plan.binning.buckets
     npan = plan.n_panels
     caps = np.asarray(plan.panel_caps, dtype=np.int64).copy()
-    nnzs = [[np.asarray(out.row_nnz[i][p], dtype=np.int64)
-             for p in range(npan)] for i in range(len(buckets))]
+    with obs.span("execute.wait"):     # the first host read of the outputs
+        nnzs = [[_to_host(out.row_nnz[i][p]).astype(np.int64)
+                 for p in range(npan)] for i in range(len(buckets))]
     cols = [list(bc) for bc in out.cols]
     vals = [list(bv) for bv in out.vals]
     args = plan.device_args()
@@ -1900,8 +1946,12 @@ def _replan_local_panels(plan: SpgemmPlan, ad, bps, out: PanelSpgemmOut,
              pop),
             lambda m=meta: _build_bucket_executor(m, plan.use_kernel,
                                                   cache))
-        c2, v2, _, _ = _invoke_executor(
-            run, dict(unit=unit, bucket=i, panel=p), ad, bps[p], tables[i])
+        with obs.span("execute.rerun"):
+            obs.count("reruns")
+            c2, v2, _, _ = _invoke_executor(
+                run, dict(unit=unit, bucket=i, panel=p), ad, bps[p],
+                tables[i])
+            obs.count("out_slots", c2.size)
         cols[i][p] = c2
         vals[i][p] = v2
 
@@ -2031,14 +2081,17 @@ def _replan_dist_panels(plan: SpgemmPlan, ad, g_val_host: np.ndarray,
             vals[i] = np.concatenate(
                 [vals[i], np.zeros(vals[i].shape[:2] + (grow,),
                                    np.float32)], axis=2)
-        for s in range(plan.row_shards):
-            d = s * npan + p
-            ad_d, bd_d = shard_operands(s, d)
-            c2, v2, _, _ = _invoke_executor(
-                run, dict(unit=unit, bucket=i, panel=p, shard=s),
-                ad_d, bd_d, jnp.asarray(t.table[d]))
-            cols[i][d, :, :new_cap] = np.asarray(c2)
-            vals[i][d, :, :new_cap] = np.asarray(v2)
+        with obs.span("execute.rerun"):
+            obs.count("reruns")
+            for s in range(plan.row_shards):
+                d = s * npan + p
+                ad_d, bd_d = shard_operands(s, d)
+                c2, v2, _, _ = _invoke_executor(
+                    run, dict(unit=unit, bucket=i, panel=p, shard=s),
+                    ad_d, bd_d, jnp.asarray(t.table[d]))
+                obs.count("out_slots", c2.size)
+                cols[i][d, :, :new_cap] = _to_host(c2)
+                vals[i][d, :, :new_cap] = _to_host(v2)
 
     for attempt in range(1, policy.rounds + 1):
         bumps = []
@@ -2057,8 +2110,8 @@ def _replan_dist_panels(plan: SpgemmPlan, ad, g_val_host: np.ndarray,
         if not bumps:
             break
         if cols is None:
-            cols = [np.asarray(c).copy() for c in out.cols]
-            vals = [np.asarray(v).copy() for v in out.vals]
+            cols = [_to_host(c).copy() for c in out.cols]
+            vals = [_to_host(v).copy() for v in out.vals]
         plan.retries = attempt
         for i, p, need, new_cap in bumps:
             rerun(i, p, new_cap, "bucket-retry")
@@ -2079,8 +2132,8 @@ def _replan_dist_panels(plan: SpgemmPlan, ad, g_val_host: np.ndarray,
                 over.append((i, p))
     if over and policy.exact_fallback:
         if cols is None:
-            cols = [np.asarray(c).copy() for c in out.cols]
-            vals = [np.asarray(v).copy() for v in out.vals]
+            cols = [_to_host(c).copy() for c in out.cols]
+            vals = [_to_host(v).copy() for v in out.vals]
         for i, p in over:
             bk = buckets[i]
             t = tables[i]
@@ -2223,27 +2276,34 @@ def execute(plan: SpgemmPlan, a, b, *, mesh=None, cache: PlanCache | None = None
                 _executor_key(plan, None),
                 lambda: _build_local_panel_executor(
                     metas, plan.use_kernel, cache, masked=plan.pop_quant))
-            bps = _panel_operands_local(plan, b)
+            with obs.span("execute.args"):
+                bps = _panel_operands_local(plan, b)
+                args = plan.device_args()[1:]
             try:
-                out = _invoke_executor(run, dict(unit="local-panels"),
-                                       ad, bps, *plan.device_args()[1:],
-                                       **wave_kw)
+                with obs.span("execute.dispatch"):
+                    out = _invoke_executor(run, dict(unit="local-panels"),
+                                           ad, bps, *args, **wave_kw)
             except StragglerError as e:
                 # a straggling fused wave replays per (bucket × panel) unit
                 # — completed units checkpoint in the recovery ledger
                 from . import recovery as recovery_mod
                 out = recovery_mod.recover_local_panels(plan, ad, bps,
                                                         cache, e)
+            obs.count("out_slots", sum(c.size for bc in out.cols for c in bc))
             if plan.retry_policy is not None or plan.retry_safety > 0:
                 out = _replan_local_panels(plan, ad, bps, out, cache)
             return out
         run = _local_executor(plan, cache)
+        with obs.span("execute.args"):
+            args = plan.device_args()
         try:
-            out = _invoke_executor(run, dict(unit="local"),
-                                   ad, bd, *plan.device_args(), **wave_kw)
+            with obs.span("execute.dispatch"):
+                out = _invoke_executor(run, dict(unit="local"), ad, bd,
+                                       *args, **wave_kw)
         except StragglerError as e:
             from . import recovery as recovery_mod
             out = recovery_mod.recover_local(plan, ad, bd, cache, e)
+        obs.count("out_slots", out.col.size)
         if plan.retry_policy is not None or plan.retry_safety > 0:
             out = _replan_local(plan, ad, bd, out, cache)
         return out
@@ -2271,13 +2331,15 @@ def execute(plan: SpgemmPlan, a, b, *, mesh=None, cache: PlanCache | None = None
             lambda: _build_panel_dist_executor(
                 metas, plan.shape_a, pg.nref, plan.shape_b[1], mesh,
                 plan.axis, plan.use_kernel, cache))
-        g_val_host = _gather_panel_values(pg, b)
-        a_col_d, g_rpt_d, g_col_d = _panel_dist_args(plan)
+        with obs.span("execute.args"):
+            g_val_host = _gather_panel_values(pg, b)
+            a_col_d, g_rpt_d, g_col_d = _panel_dist_args(plan)
+            args = (ad.rpt, ad.val, a_col_d, g_rpt_d, g_col_d,
+                    jnp.asarray(g_val_host)) + plan.device_args()
         try:
-            flat = _invoke_executor(run, dict(unit="dist-panels"),
-                                    ad.rpt, ad.val, a_col_d, g_rpt_d,
-                                    g_col_d, jnp.asarray(g_val_host),
-                                    *plan.device_args(), **wave_kw)
+            with obs.span("execute.dispatch"):
+                flat = _invoke_executor(run, dict(unit="dist-panels"),
+                                        *args, **wave_kw)
         except ShardFailureError as e:
             # a failed fused wave re-executes per (bucket × device) unit
             # against the SAME host-side gathered operands; a persistently
@@ -2285,12 +2347,7 @@ def execute(plan: SpgemmPlan, a, b, *, mesh=None, cache: PlanCache | None = None
             from . import recovery as recovery_mod
             return recovery_mod.recover_dist_panels(plan, ad, g_val_host,
                                                     cache, e)
-        cols, vals, nnzs = flat[0::3], flat[1::3], flat[2::3]
-        overflow = np.zeros(plan.num_shards, dtype=np.int64)
-        for t, n in zip(plan.shard_tables, nnzs):
-            over = np.maximum(np.asarray(n, dtype=np.int64) - t.capacity, 0)
-            overflow += np.where(t.valid, over, 0).sum(axis=1)
-        out = DistSpgemmOut(tuple(cols), tuple(vals), tuple(nnzs), overflow)
+        out = _dist_out(plan, flat)
         if plan.retry_policy is not None or plan.retry_safety > 0:
             out = _replan_dist_panels(plan, ad, g_val_host, out, cache)
         return out
@@ -2300,24 +2357,36 @@ def execute(plan: SpgemmPlan, a, b, *, mesh=None, cache: PlanCache | None = None
         _executor_key(plan, mesh),
         lambda: _build_dist_executor(metas, mesh, plan.axis,
                                      plan.use_kernel, cache))
+    with obs.span("execute.args"):
+        args = plan.device_args()
     try:
-        flat = _invoke_executor(run, dict(unit="dist"),
-                                ad, bd, *plan.device_args(), **wave_kw)
+        with obs.span("execute.dispatch"):
+            flat = _invoke_executor(run, dict(unit="dist"), ad, bd, *args,
+                                    **wave_kw)
     except ShardFailureError as e:
         # the fused SPMD wave is all-or-nothing; recovery re-executes it as
         # per-(bucket × shard) units, checkpointing each as it lands, and
         # re-homes a lost shard's rows across survivors (DESIGN.md §12)
         from . import recovery as recovery_mod
         return recovery_mod.recover_dist(plan, ad, bd, cache, e)
-    cols, vals, nnzs = flat[0::3], flat[1::3], flat[2::3]
-    overflow = np.zeros(plan.num_shards, dtype=np.int64)
-    for t, n in zip(plan.shard_tables, nnzs):
-        over = np.maximum(np.asarray(n, dtype=np.int64) - t.capacity, 0)
-        overflow += np.where(t.valid, over, 0).sum(axis=1)
-    out = DistSpgemmOut(tuple(cols), tuple(vals), tuple(nnzs), overflow)
+    out = _dist_out(plan, flat)
     if plan.retry_policy is not None or plan.retry_safety > 0:
         out = _replan_dist(plan, ad, bd, out, cache, mesh)
     return out
+
+
+def _dist_out(plan: SpgemmPlan, flat: tuple) -> DistSpgemmOut:
+    """A shard_map executor's flat outputs as a :class:`DistSpgemmOut`,
+    with the per-shard overflow read from the true ``row_nnz``."""
+    cols, vals, nnzs = flat[0::3], flat[1::3], flat[2::3]
+    obs.count("out_slots", sum(c.size for c in cols))
+    overflow = np.zeros(plan.num_shards, dtype=np.int64)
+    with obs.span("execute.wait"):     # the first host read of the outputs
+        for t, n in zip(plan.shard_tables, nnzs):
+            n = _to_host(n).astype(np.int64)
+            overflow += np.where(t.valid, np.maximum(n - t.capacity, 0),
+                                 0).sum(axis=1)
+    return DistSpgemmOut(tuple(cols), tuple(vals), tuple(nnzs), overflow)
 
 
 # --------------------------------------------------------------------------- #
@@ -2347,51 +2416,45 @@ def reassemble(plan: SpgemmPlan, out, ncols: int | None = None, *,
     """
     ncols = int(ncols if ncols is not None else plan.shape_b[1])
     nrows = plan.shape_a[0]
-    rows_out = [np.zeros(0, np.int64)]
-    cols_out = [np.zeros(0, np.int64)]
-    vals_out = [np.zeros(0, np.float32)]
-    if isinstance(out, PanelSpgemmOut):
-        # panels partition the column space: collecting every (bucket, panel)
-        # block as COO and letting from_coo's stable sort order the entries
-        # restores the single-matrix layout bitwise (DESIGN.md §8)
-        _check_overflow(int(out.overflow), [int(out.overflow)], on_overflow)
-        for i, bk in enumerate(plan.binning.buckets):
-            if bk.n_rows == 0:
-                continue
-            for p in range(plan.n_panels):
-                c_b = np.asarray(out.cols[i][p])[:bk.n_rows]
-                v_b = np.asarray(out.vals[i][p])[:bk.n_rows]
-                m = c_b != COL_SENTINEL
-                counts = m.sum(axis=1)
-                rows_out.append(np.repeat(bk.rows.astype(np.int64), counts))
-                cols_out.append(c_b[m].astype(np.int64))
-                vals_out.append(v_b[m])
+    # host copies first: (row ids, col block, val block, row validity)
+    with obs.span("reassemble.copy"):
+        if isinstance(out, PanelSpgemmOut):
+            # panels partition the column space: collecting every (bucket,
+            # panel) block as COO and letting from_coo's stable sort order
+            # the entries restores the single-matrix layout bitwise (§8)
+            overflow = int(_to_host(out.overflow))
+            _check_overflow(overflow, [overflow], on_overflow)
+            blocks = [(bk.rows, _to_host(out.cols[i][p])[:bk.n_rows],
+                       _to_host(out.vals[i][p])[:bk.n_rows], None)
+                      for i, bk in enumerate(plan.binning.buckets)
+                      if bk.n_rows for p in range(plan.n_panels)]
+        elif isinstance(out, DistSpgemmOut):
+            _check_overflow(int(out.shard_overflow.sum()), out.shard_overflow,
+                            on_overflow)
+            blocks = [(t.table.reshape(-1),
+                       _to_host(c_b).reshape(-1, t.capacity),  # (S·rows_pb,
+                       _to_host(v_b).reshape(-1, t.capacity),  #  cap)
+                       t.valid.reshape(-1))
+                      for t, c_b, v_b in zip(plan.shard_tables, out.cols,
+                                             out.vals)]
+        else:
+            overflow = int(_to_host(out.overflow))
+            _check_overflow(overflow, [overflow], on_overflow)
+            blocks = [(np.arange(nrows), _to_host(out.col),
+                       _to_host(out.val), None)]
+    with obs.span("reassemble.to_csr"):
+        rows_out = [np.zeros(0, np.int64)]
+        cols_out = [np.zeros(0, np.int64)]
+        vals_out = [np.zeros(0, np.float32)]
+        for rows, c_b, v_b, valid in blocks:
+            m = c_b != COL_SENTINEL
+            if valid is not None:
+                m &= valid[:, None]
+            rows_out.append(np.repeat(rows.astype(np.int64, copy=False),
+                                      m.sum(axis=1)))
+            cols_out.append(c_b[m].astype(np.int64))
+            vals_out.append(v_b[m])
         return CSR.from_coo(np.concatenate(rows_out),
                             np.concatenate(cols_out),
                             np.concatenate(vals_out).astype(np.float32),
                             (nrows, ncols), dedup=False, validate=False)
-    if isinstance(out, DistSpgemmOut):
-        _check_overflow(int(out.shard_overflow.sum()), out.shard_overflow,
-                        on_overflow)
-        for t, c_b, v_b in zip(plan.shard_tables, out.cols, out.vals):
-            cap = t.capacity
-            c_b = np.asarray(c_b).reshape(-1, cap)     # (S·rows_pb, cap)
-            v_b = np.asarray(v_b).reshape(-1, cap)
-            m = (c_b != COL_SENTINEL) & t.valid.reshape(-1)[:, None]
-            counts = m.sum(axis=1)
-            rows_out.append(np.repeat(
-                t.table.reshape(-1).astype(np.int64), counts))
-            cols_out.append(c_b[m].astype(np.int64))
-            vals_out.append(v_b[m])
-    else:
-        _check_overflow(int(out.overflow), [int(out.overflow)], on_overflow)
-        col = np.asarray(out.col)
-        val = np.asarray(out.val)
-        m = col != COL_SENTINEL
-        counts = m.sum(axis=1)
-        rows_out.append(np.repeat(np.arange(nrows, dtype=np.int64), counts))
-        cols_out.append(col[m].astype(np.int64))
-        vals_out.append(val[m])
-    return CSR.from_coo(np.concatenate(rows_out), np.concatenate(cols_out),
-                        np.concatenate(vals_out).astype(np.float32),
-                        (nrows, ncols), dedup=False, validate=False)

@@ -58,6 +58,7 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.core import faults as faults_mod
 from repro.core import plan as plan_mod
 from repro.core import validate as validate_mod
@@ -321,7 +322,8 @@ class SpgemmService:
             # malformed operands are contained at the front door — a NaN
             # smuggled into values never reaches planning or the queue
             try:
-                validate_mod.validate_pair(a, b)
+                with obs.request(req.id), obs.span("submit.validate"):
+                    validate_mod.validate_pair(a, b)
             except SpgemmError as e:
                 self._finish(req, RequestState.FAILED, error=e)
                 return req
@@ -338,17 +340,19 @@ class SpgemmService:
         if req.plan is not None:
             return True
         try:
-            req.plan = plan_mod.plan_spgemm(
-                req.a, req.b, safety=self.config.safety,
-                seed=self.config.seed, pop_quant=self.config.pop_quant,
-                template=self.config.template, registry=self._registry,
-                n_panels=self.config.n_panels,
-                use_kernel=self.config.use_kernel,
-                retry_policy=(req.retry_policy if req.retry_policy is not None
-                              else self.config.retry_policy),
-                mesh=self.config.mesh,
-                dispatch_budget=self.config.dispatch_budget,
-                validate=False)            # validated at submit
+            with obs.request(req.id), obs.span("plan"):
+                req.plan = plan_mod.plan_spgemm(
+                    req.a, req.b, safety=self.config.safety,
+                    seed=self.config.seed, pop_quant=self.config.pop_quant,
+                    template=self.config.template, registry=self._registry,
+                    n_panels=self.config.n_panels,
+                    use_kernel=self.config.use_kernel,
+                    retry_policy=(req.retry_policy
+                                  if req.retry_policy is not None
+                                  else self.config.retry_policy),
+                    mesh=self.config.mesh,
+                    dispatch_budget=self.config.dispatch_budget,
+                    validate=False)            # validated at submit
         except SpgemmError as e:
             self._finish(req, RequestState.FAILED, error=e)
             return False
@@ -437,8 +441,12 @@ class SpgemmService:
             return
         self._set_state(req, RequestState.EXECUTING, now)
         try:
-            out = plan_mod.execute(req.plan, req.a, req.b, cache=self._cache)
-            c = plan_mod.reassemble(req.plan, out)
+            with obs.request(req.id):
+                with obs.span("execute"):
+                    out = plan_mod.execute(req.plan, req.a, req.b,
+                                           cache=self._cache)
+                with obs.span("reassemble"):
+                    c = plan_mod.reassemble(req.plan, out)
         except CapacityExhaustedError as e:
             if req.attempts == 0:
                 # one requeue at the escalated policy (exact fallback on):
