@@ -8,8 +8,9 @@ Flow (the paper's motivating use-case, Section I):
                                   predicted-size buffers, overflow-reported.
 
 The numeric accumulation mirrors the symbolic TPU adaptation: expand products
-into a static (rows, DA*DB) buffer, sort by column carrying values, detect
-segment boundaries, scatter-add into per-row slots.  Overflow (a row whose
+into a static (rows, DA*DB) buffer, sort by column carrying values, sum each
+run with a segmented scan, and place the run sums into per-row slots with a
+second keyed sort (no scatter).  Overflow (a row whose
 true nnz exceeds the predicted capacity) is counted and returned so callers
 can re-run with a bumped plan — the compiled-program analogue of realloc.
 """
@@ -24,6 +25,7 @@ import numpy as np
 
 from .csr import CSRDevice, COL_SENTINEL, expand_products, pad_row_ids
 from .binning import ROUTE_SPA, ROUTE_BIN
+from repro.kernels.sortnet import segmented_run_sums
 
 
 class SpGEMMOut(NamedTuple):
@@ -60,50 +62,65 @@ def gather_products(a: CSRDevice, b: CSRDevice, rows: jax.Array,
                            rownnz_b=rownnz_b, with_values=True)
 
 
+def place_sorted(keys, vals, row_capacity: int):
+    """Write each row's entries into ``row_capacity`` slots by one keyed sort.
+
+    ``keys`` holds an entry's column on the slots that carry one and
+    ``COL_SENTINEL`` everywhere else, where ``vals`` must be 0.  Sorting the
+    (key, value) pairs along the row moves the entries to the front in
+    ascending column order and the sentinels to the tail; the first
+    ``row_capacity`` slots are kept, sentinel/0 padded when the row is
+    narrower.  Entries carry distinct keys and the sentinels identical
+    payloads, so the sort need not be stable.  This is the compaction of
+    every accumulator route: a vectorised sort, where slot-indexed
+    ``.at[].add/.min`` scatters would serialise on the TPU.
+    """
+    keys, vals = jax.lax.sort((keys, vals), dimension=keys.ndim - 1,
+                              is_stable=False, num_keys=1)
+    if keys.shape[-1] >= row_capacity:
+        return keys[:, :row_capacity], vals[:, :row_capacity]
+    return pad_to_capacity(keys, vals, row_capacity)
+
+
 def _accumulate_block(cols, vals, row_capacity: int):
-    """Sort-merge accumulation for one block of rows."""
-    order = jnp.argsort(cols, axis=-1)
-    c_s = jnp.take_along_axis(cols, order, axis=-1)
-    v_s = jnp.take_along_axis(vals, order, axis=-1)
-    valid = c_s != COL_SENTINEL
-    newseg = jnp.concatenate(
-        [valid[:, :1],
-         (c_s[:, 1:] != c_s[:, :-1]) & valid[:, 1:]], axis=-1)
-    seg = jnp.cumsum(newseg.astype(jnp.int32), axis=-1) - 1       # distinct id
-    row_nnz = seg[:, -1] + 1
-    # scatter: invalid or overflowing slots go out of bounds (mode=drop)
-    seg_sc = jnp.where(valid, seg, row_capacity)
-    bs = cols.shape[0]
-    rows_ix = jnp.broadcast_to(jnp.arange(bs)[:, None], seg_sc.shape)
-    out_val = jnp.zeros((bs, row_capacity), jnp.float32).at[rows_ix, seg_sc].add(
-        v_s, mode="drop")
-    out_col = jnp.full((bs, row_capacity), COL_SENTINEL, jnp.int32).at[
-        rows_ix, seg_sc].min(c_s, mode="drop")
+    """Sort-merge (ESC) accumulation for one block of rows.
+
+    A stable keyed sort orders each row's products by column (equal columns
+    keep their gather order); the log-step segmented scan of
+    ``kernels.sortnet`` places each run's sum at the run's first slot, its
+    addition tree fixed by the run alone, so a row's values do not depend on
+    the padded lane width or ``block_rows``; ``place_sorted`` then moves the
+    run heads into the row's slots.  ``row_nnz`` counts the runs (may exceed
+    ``row_capacity``).
+    """
+    c_s, v_s = jax.lax.sort((cols, vals), dimension=cols.ndim - 1,
+                            is_stable=True, num_keys=1)
+    first, run_sums = segmented_run_sums(c_s, v_s, COL_SENTINEL)
+    out_col, out_val = place_sorted(jnp.where(first, c_s, COL_SENTINEL),
+                                    jnp.where(first, run_sums, 0.0),
+                                    row_capacity)
+    row_nnz = first.sum(axis=-1, dtype=jnp.int32)
     overflow = jnp.maximum(row_nnz - row_capacity, 0).sum()
     return out_col, out_val, row_nnz, overflow
 
 
-def _dense_accumulate_block(cols, vals, ncols_b: int, row_capacity: int,
-                            span: int = 0):
-    """Dense-SPA accumulation for one block of rows (jnp path, DESIGN §5).
+def _window_accumulate_block(cols, vals, n: int, row_capacity: int,
+                             relative: bool):
+    """Dense-window accumulation shared by the SPA and BIN jnp paths.
 
-    Value products scatter-add into a dense accumulator; structural presence
-    is tracked separately (a run summing to 0.0 is still an output entry,
-    exactly as on the sort path), then both compact into the predicted
-    ``row_capacity`` slots in ascending-column order — the same layout the
-    sort path emits.  Sentinel-padded slots scatter out of range and are
-    dropped.  With ``span`` (the planner's per-row column-extent bound) the
-    accumulator covers only the pow2-padded extent, addressed relative to
-    each row's minimum column — the banded/FEM lever of the SPA route.
+    Value products scatter-add into an ``(rows, n)`` window and structural
+    presence is tracked separately (a run summing to 0.0 is still an output
+    entry, exactly as on the sort path); both then compact into the
+    predicted ``row_capacity`` slots through :func:`compact_dense`.
+    Sentinel-padded products scatter out of range and are dropped.  With
+    ``relative`` the window is addressed from each row's minimum column
+    (``kernels.accumulator.extent_relative``).
     """
-    from .binning import ceil_pow2
-    bs = cols.shape[0]
     lo = None
-    n = min(int(span), ncols_b) if span else ncols_b
-    if span:
+    if relative:
         from repro.kernels.accumulator import extent_relative
         cols, lo = extent_relative(cols)
-        n = ceil_pow2(n)
+    bs = cols.shape[0]
     rows_ix = jnp.broadcast_to(jnp.arange(bs)[:, None], cols.shape)
     acc = jnp.zeros((bs, n), jnp.float32).at[rows_ix, cols].add(
         vals, mode="drop")
@@ -112,78 +129,57 @@ def _dense_accumulate_block(cols, vals, ncols_b: int, row_capacity: int,
     return compact_dense(acc, present, row_capacity, col_offset=lo)
 
 
+def _dense_accumulate_block(cols, vals, ncols_b: int, row_capacity: int,
+                            span: int = 0):
+    """Dense-SPA accumulation for one block of rows (jnp path, DESIGN §5).
+
+    The window covers B's column space, or with ``span`` (the planner's
+    per-row column-extent bound) only the pow2-padded extent, addressed
+    relative to each row's minimum column — the banded/FEM lever of the SPA
+    route.  The output layout is the sort path's: ascending columns.
+    """
+    from .binning import ceil_pow2
+    n = ceil_pow2(min(int(span), ncols_b)) if span else ncols_b
+    return _window_accumulate_block(cols, vals, n, row_capacity,
+                                    relative=bool(span))
+
+
 def _bin_accumulate_block(cols, vals, row_capacity: int, tile_n: int,
                           n_tiles: int):
     """Propagation-blocking accumulation for one block of rows (jnp path).
 
     The bin layout of DESIGN.md §11: value products scatter into an
     extent-relative dense window of ``n_tiles`` bins × ``tile_n`` columns,
-    each bin merges independently (``compact_dense`` per bin, capacity
-    ``min(tile_n, row_capacity)``), and the per-bin compacted runs — already
-    ascending, bins ordered by column range — concatenate through one final
-    validity compaction into the predicted ``row_capacity`` slots.
-
-    The retained set equals the sort path's global first-``row_capacity``
-    ascending rule: a bin only truncates when ``row_capacity < tile_n`` and
-    the bin alone exceeds it, in which case the dropped entries sit past the
-    row's capacity in the global order too.  ``row_nnz`` sums the TRUE
-    per-bin structural counts, so overflow accounting matches ESC exactly.
+    and one sorted pass over the whole window places its present columns
+    into the predicted ``row_capacity`` slots.  Bins are ordered by column
+    range, so that pass keeps the sort path's global first-``row_capacity``
+    ascending set, and ``row_nnz`` is the true structural count: overflow
+    accounting matches ESC exactly.
     """
-    from repro.kernels.accumulator import extent_relative
-    bs = cols.shape[0]
-    n = tile_n * n_tiles
-    rel, lo = extent_relative(cols)
-    rows_ix = jnp.broadcast_to(jnp.arange(bs)[:, None], rel.shape)
-    acc = jnp.zeros((bs, n), jnp.float32).at[rows_ix, rel].add(
-        vals, mode="drop")
-    present = jnp.zeros((bs, n), jnp.bool_).at[rows_ix, rel].set(
-        True, mode="drop")
-    cap_bin = min(tile_n, row_capacity)
-    offs = (lo[:, None].astype(jnp.int32)
-            + jnp.arange(n_tiles, dtype=jnp.int32)[None, :] * tile_n
-            ).reshape(-1)
-    c_b, v_b, nnz_b, _ = compact_dense(
-        acc.reshape(bs * n_tiles, tile_n),
-        present.reshape(bs * n_tiles, tile_n), cap_bin, col_offset=offs)
-    c_cat = c_b.reshape(bs, n_tiles * cap_bin)
-    v_cat = v_b.reshape(bs, n_tiles * cap_bin)
-    valid = c_cat != COL_SENTINEL
-    seg = jnp.cumsum(valid.astype(jnp.int32), axis=-1) - 1
-    seg_sc = jnp.where(valid, seg, row_capacity)
-    rows2 = jnp.broadcast_to(jnp.arange(bs)[:, None], c_cat.shape)
-    out_val = jnp.zeros((bs, row_capacity), jnp.float32).at[
-        rows2, seg_sc].add(v_cat, mode="drop")
-    out_col = jnp.full((bs, row_capacity), COL_SENTINEL, jnp.int32).at[
-        rows2, seg_sc].min(c_cat, mode="drop")
-    row_nnz = nnz_b.reshape(bs, n_tiles).sum(axis=1)
-    overflow = jnp.maximum(row_nnz - row_capacity, 0).sum()
-    return out_col, out_val, row_nnz, overflow
+    return _window_accumulate_block(cols, vals, tile_n * n_tiles,
+                                    row_capacity, relative=True)
 
 
 def compact_dense(acc, present, row_capacity: int, col_offset=None):
     """Dense accumulator (+ presence mask) → predicted-capacity buffers.
 
-    Shared by the jnp SPA path and the Pallas SPA kernel wrapper: ascending
-    columns, ``row_nnz`` = structural count (may exceed capacity), overflow
-    slots dropped — bit-identical structure to the ESC compaction.
-    ``col_offset`` (per-row int32) restores absolute column ids when the
-    accumulator was addressed relative to each row's minimum column (the
-    extent-relative layout of ``kernels.accumulator.spa_numeric_pallas``).
+    Shared by the jnp SPA/BIN paths and the Pallas SPA/BIN kernel wrappers:
+    each present column, with its sum, is placed by :func:`place_sorted`
+    (ascending columns, overflow slots past ``row_capacity`` dropped) —
+    bit-identical structure to the ESC compaction; ``row_nnz`` is the
+    structural count (may exceed capacity).  ``acc`` is 0 wherever
+    ``present`` is False (nothing was added there), as the placement
+    requires of its sentinel slots.  ``col_offset`` (per-row int32)
+    restores absolute column ids when the accumulator was addressed relative
+    to each row's minimum column (the extent-relative layout of
+    ``kernels.accumulator.spa_numeric_pallas``).
     """
-    bs, n = acc.shape
-    pres_i = present.astype(jnp.int32)
-    seg = jnp.cumsum(pres_i, axis=-1) - 1
-    seg_sc = jnp.where(present, seg, row_capacity)
-    rows_ix = jnp.broadcast_to(jnp.arange(bs)[:, None], acc.shape)
-    out_val = jnp.zeros((bs, row_capacity), jnp.float32).at[
-        rows_ix, seg_sc].add(acc, mode="drop")
-    col_ids = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :],
-                               acc.shape)
+    col_ids = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
     if col_offset is not None:
         col_ids = col_ids + col_offset[:, None].astype(jnp.int32)
-    out_col = jnp.full((bs, row_capacity), COL_SENTINEL, jnp.int32).at[
-        rows_ix, seg_sc].min(col_ids, mode="drop")
-    row_nnz = seg[:, -1] + 1
+    out_col, out_val = place_sorted(jnp.where(present, col_ids, COL_SENTINEL),
+                                    acc, row_capacity)
+    row_nnz = present.sum(axis=-1, dtype=jnp.int32)
     overflow = jnp.maximum(row_nnz - row_capacity, 0).sum()
     return out_col, out_val, row_nnz, overflow
 

@@ -339,8 +339,8 @@ def spa_numeric_pallas(a_rpt, a_col, a_val, b_rpt, b_col, b_val, rows, *,
     ``(acc, present, lo)`` with ``acc``/``present`` of shape
     ``(R, n_tiles·tile_n)`` covering each row's product-column extent
     relative to its own minimum column ``lo``; compaction into the predicted
-    capacities is the cheap XLA pass ``core.spgemm.compact_dense`` (the same
-    kernel/XLA split as the ESC numeric path).
+    capacities is the XLA keyed-sort placement ``core.spgemm.compact_dense``
+    (the same kernel/XLA split as the ESC numeric path).
 
     ``n_tiles·tile_n`` must bound every row's column extent — the planner
     guarantees that for bucket calls (``RowBucket.span``); the default

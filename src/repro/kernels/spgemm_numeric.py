@@ -6,11 +6,11 @@ key/value pairs, then compute per-run value sums with the log-step segmented
 scan.  The kernel emits the *uncompacted* sorted buffer: sorted columns, a
 first-of-run mask, and run-sums placed at each run's first slot.
 
-The O(F log F) sort + O(F log F) segmented scan — the expensive part — stays
-in the kernel; the O(F) compaction into the predicted-capacity CSR buffers is
-a cheap XLA scatter outside (see ``repro.core.spgemm`` / ``ops.py``).  This
-split keeps the kernel free of VMEM scatters while the MXU-unfriendly memory
-traffic is still one pass.
+The O(F log F) sort + O(F log F) segmented scan stays in the kernel; the
+compaction into the predicted-capacity CSR buffers is an XLA keyed sort
+outside (``compact`` → ``repro.core.spgemm.place_sorted``).  This split keeps
+the kernel free of VMEM scatters while the MXU-unfriendly memory traffic is
+still one pass.
 """
 from __future__ import annotations
 
@@ -98,16 +98,13 @@ def spgemm_numeric_pallas(a_rpt, a_col, a_val, b_rpt, b_col, b_val, rows, *,
 
 
 def compact(cols, vals, first, row_capacity: int):
-    """XLA-side compaction into predicted-capacity buffers (cheap O(F))."""
-    seg = jnp.cumsum(first, axis=-1) - 1
-    valid = first.astype(bool)
-    seg_sc = jnp.where(valid, seg, row_capacity)
-    r = cols.shape[0]
-    rows_ix = jnp.broadcast_to(jnp.arange(r)[:, None], seg_sc.shape)
-    out_val = jnp.zeros((r, row_capacity), jnp.float32).at[
-        rows_ix, seg_sc].add(vals, mode="drop")
-    out_col = jnp.full((r, row_capacity), COL_SENTINEL, jnp.int32).at[
-        rows_ix, seg_sc].min(cols, mode="drop")
-    row_nnz = seg[:, -1] + 1
+    """XLA-side compaction into predicted-capacity buffers: the run heads
+    are placed by one keyed sort (``core.spgemm.place_sorted``), no scatter.
+    ``vals`` is already 0 off the run heads (the kernel writes it so)."""
+    from repro.core.spgemm import place_sorted
+    first = first.astype(bool)
+    out_col, out_val = place_sorted(jnp.where(first, cols, COL_SENTINEL),
+                                    vals, row_capacity)
+    row_nnz = first.sum(axis=-1, dtype=jnp.int32)
     overflow = jnp.maximum(row_nnz - row_capacity, 0).sum()
     return out_col, out_val, row_nnz, overflow

@@ -18,7 +18,8 @@ except ImportError:  # minimal CI image — deterministic tests must still run
     from hypothesis_shim import given, settings, st
 
 from repro.sparse import random as sprand
-from repro.core import binning, csr, predictor, spgemm
+from repro.sparse.formats import CSR, spgemm_dense_oracle
+from repro.core import binning, csr, oracle, predictor, spgemm
 from repro.core.flop import flop_per_row
 from repro.kernels import ops, ref
 
@@ -206,6 +207,111 @@ def test_forced_routes_agree_numeric(name, a, b):
         np.testing.assert_array_equal(np.asarray(outs["esc"].row_nnz),
                                       np.asarray(outs[route].row_nnz))
         assert int(outs["esc"].overflow) == int(outs[route].overflow)
+
+
+def _edge_pair(case):
+    """(a, b, rows, row_capacity, block_rows) for one compaction edge case."""
+    rng = np.random.default_rng(61)
+
+    def mat(r, c, shape):
+        r, c = np.asarray(r), np.asarray(c)
+        return CSR.from_coo(r, c, rng.uniform(0.5, 1.5, r.size), shape)
+
+    if case == "long_run":
+        # row 0 reaches 40 B rows that all hold column 7: one 40-long run
+        a = mat(np.r_[np.zeros(40, int), np.arange(1, 41)],
+                np.r_[np.arange(40), np.arange(40)], (41, 40))
+        b = mat(np.r_[np.arange(40), np.arange(40)],
+                np.r_[np.full(40, 7), rng.integers(0, 96, 40)], (40, 96))
+        return a, b, np.arange(41), 8, 16
+    if case == "empty_rows":
+        a = sprand.erdos_renyi(96, 80, 3, seed=62)
+        keep = np.repeat(np.arange(96), a.row_nnz) % 3 != 0
+        a = mat(np.repeat(np.arange(96), a.row_nnz)[keep], a.col[keep],
+                (96, 80))
+        b = sprand.erdos_renyi(80, 120, 3, seed=63)
+        b = mat(np.repeat(np.arange(80), b.row_nnz)[np.repeat(
+            np.arange(80) % 4 != 0, b.row_nnz)], b.col[np.repeat(
+                np.arange(80) % 4 != 0, b.row_nnz)], (80, 120))
+        return a, b, np.arange(96), 16, 32
+    a = sprand.erdos_renyi(120, 100, 4, seed=64)
+    b = sprand.erdos_renyi(100, 150, 3, seed=65)
+    if case == "overflow":
+        return a, b, np.arange(0, 120, 2), 4, 16
+    if case == "wide_capacity":      # more slots than gathered lanes
+        w = int(a.row_nnz.max()) * int(b.row_nnz.max())
+        return a, b, np.arange(120), 2 * w, 32
+    # pad_rows: 37 rows in blocks of 16, the last (repeated) row overflowing
+    rows = np.r_[np.arange(36), int(np.argmax(a.row_nnz))]
+    return a, b, rows, 6, 16
+
+
+_EDGE_CASES = ("overflow", "long_run", "empty_rows", "wide_capacity",
+               "pad_rows")
+
+
+@pytest.mark.parametrize("route", ("esc", "spa", "bin"))
+@pytest.mark.parametrize("case", _EDGE_CASES)
+def test_route_compaction_edge_cases_match_oracle(case, route):
+    """Every route's sorted placement against the numpy oracle: ``col``,
+    ``row_nnz`` and ``overflow`` exact (the first ``row_capacity`` distinct
+    columns ascending, true counts, overflow over real rows only), ``val``
+    to float tolerance."""
+    a, b, rows, cap, block = _edge_pair(case)
+    ad, bd = csr.to_device(a), csr.to_device(b)
+    kw = dict(row_capacity=cap, max_deg_a=max(1, int(a.row_nnz.max())),
+              max_deg_b=max(1, int(b.row_nnz.max())), block_rows=block)
+    rows_d = jnp.asarray(rows, jnp.int32)
+    if route == "esc":
+        out = spgemm.spgemm_rows(ad, bd, rows_d, **kw)
+    elif route == "spa":
+        out = spgemm.spgemm_rows_spa(ad, bd, rows_d, **kw)
+    else:
+        out = spgemm.spgemm_rows_bin(
+            ad, bd, rows_d, **kw, tile_n=32,
+            n_tiles=binning.ceil_pow2(b.ncols) // 32)
+    owner, col = oracle.expand_products(a, b, rows)
+    dense = spgemm_dense_oracle(a, b)
+    want_c = np.full((len(rows), cap), csr.COL_SENTINEL, np.int64)
+    want_v = np.zeros((len(rows), cap), np.float32)
+    want_n = np.zeros(len(rows), np.int64)
+    for i, r in enumerate(rows):
+        cs = np.unique(col[owner == i])
+        want_n[i] = cs.size
+        want_c[i, :min(cap, cs.size)] = cs[:cap]
+        want_v[i, :min(cap, cs.size)] = dense[r, cs[:cap]]
+    np.testing.assert_array_equal(np.asarray(out.col), want_c)
+    np.testing.assert_array_equal(np.asarray(out.row_nnz), want_n)
+    assert int(out.overflow) == int(np.maximum(want_n - cap, 0).sum())
+    np.testing.assert_allclose(np.asarray(out.val), want_v, rtol=1e-5,
+                               atol=1e-5)
+    if case == "overflow":
+        assert (want_n > cap).any()
+    if case == "empty_rows":
+        assert (want_n == 0).any()
+    if case == "long_run":
+        assert (col[owner == 0] == 7).sum() == 40
+    if case == "pad_rows":
+        assert len(rows) % block and want_n[-1] > cap
+
+
+@pytest.mark.parametrize("fn,extra,scatters", [
+    (spgemm.spgemm_rows, {}, 0),
+    (spgemm.spgemm_rows_spa, {}, 2),
+    (spgemm.spgemm_rows_bin, dict(tile_n=32, n_tiles=4), 2),
+], ids=("esc", "spa", "bin"))
+def test_lowered_executors_place_by_sort(fn, extra, scatters):
+    """The compaction is a keyed sort, not a scatter: the ESC route lowers
+    with no scatter at all, SPA and BIN with only their dense window's two
+    (value add and presence set)."""
+    a = sprand.power_law(64, 64, 4, 1.5, seed=1)
+    ad = csr.to_device(a)
+    mda = int(a.row_nnz.max())
+    text = fn.lower(ad, ad, jnp.arange(64, dtype=jnp.int32), row_capacity=8,
+                    max_deg_a=mda, max_deg_b=mda, block_rows=16,
+                    **extra).as_text()
+    assert text.count('"stablehlo.scatter"') == scatters
+    assert text.count('"stablehlo.sort"') >= 1
 
 
 def test_forced_routes_agree_kernel_path():
