@@ -11,8 +11,10 @@ a file of its own and is found by the name ``BENCHMARK.json`` gives it:
   ``plan_s.tenants``) may share the reader of its stem (``plan_s.py``).
 
 The system under test is ``SpgemmService`` with its own defaults, but for
-the chip's memory as its device budget; each request is one product
-C = A·A, the paper's protocol.
+the cell's chips: a cell of one chip runs it on the first device, with that
+chip's memory as its device budget; a cell of more runs it on a 1-D
+``data`` mesh of the first ``chips`` devices, with the mesh's memory as its
+budget.  Each request is one product C = A·A, the paper's protocol.
 """
 from __future__ import annotations
 
@@ -167,6 +169,7 @@ class Run:
     peak_bytes: int     # PeakAt: at the traffic's ``peak_after_answers``
     window_compiles: int
     device_kind: str
+    chips: int = 1      # the cell's devices, which share the work
     spans: object = None        # tracing.Spans, traced run only
     trace: dict | None = None   # tracing.summarize(), traced run only
     nnz_c: dict = dataclasses.field(default_factory=dict)
@@ -279,27 +282,57 @@ class Cell:
     devices: list
 
 
-def set_up(config: dict, traffic: dict, *, seed: int, rows: int | None,
-           clock, log) -> Cell:
-    """Operand pools from the seed, the service, and its warm-up."""
+class TooFewDevices(RuntimeError):
+    """The cell asks for more devices than JAX finds."""
+
+
+def cell_devices(chips: int) -> list:
+    """The first ``chips`` of JAX's devices.  Fewer is an error, never a
+    smaller run (a rehearsal on the CPU gets more from ``XLA_FLAGS=
+    --xla_force_host_platform_device_count=<chips>``)."""
     import jax
+    devices = jax.devices()
+    if len(devices) < chips:
+        raise TooFewDevices(
+            f"the cell needs {chips} devices; JAX found {len(devices)} "
+            f"{devices[0].platform!r} device(s)")
+    return devices[:chips]
+
+
+def device_budget(devices) -> int | None:
+    """The sum of the devices' ``bytes_limit`` (one chip's for a one-chip
+    cell), or ``None`` where a device reports none (the CPU).  The sum is
+    what a mesh holds: ``admission.estimate_cost`` prices a plan made on a
+    mesh as its whole product, every shard's output slots together
+    (``planned_bytes``: ``shard_slots() * num_shards``)."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in devices]
+    return sum(int(x) for x in limits) if all(limits) else None
+
+
+def set_up(config: dict, traffic: dict, *, chips: int, seed: int,
+           rows: int | None, clock, log) -> Cell:
+    """Operand pools from the seed, the service on the cell's devices, and
+    its warm-up."""
+    from jax.sharding import Mesh
     from repro.serve.spgemm_service import ServiceConfig, SpgemmService
 
     compiles = CompileClock()
-    devices = jax.devices()
+    devices = cell_devices(chips)
     sizes = loads.size_rows(config, traffic, rows)
     pools = loads.make_pools(config, traffic, seed, rows)
     mats = [[_csr(op, r) for op in pool] for pool, r in zip(pools, sizes)]
     log(f"operands: rows {sizes}, {traffic['pool_per_size']} per size, "
         f"nnz {[int(np.mean([p[1].size for p in pool])) for pool in pools]}"
         f" (mean per size)")
-    budget = (devices[0].memory_stats() or {}).get("bytes_limit")
+    budget = device_budget(devices)
+    # one chip: no mesh, the local executors; more: the distributed ones
+    mesh = Mesh(np.array(devices), ("data",)) if chips > 1 else None
     # the sampler keeps the service's default seed: with the run's seed the
     # sampled prediction moves pow2 capacities, so each new seed compiled
     # new executors in set-up and changed the work (power law)
     svc = SpgemmService(ServiceConfig(
-        device_budget_bytes=int(budget or ServiceConfig.device_budget_bytes)),
-        clock=clock)
+        device_budget_bytes=int(budget or ServiceConfig.device_budget_bytes),
+        mesh=mesh), clock=clock)
     warm_up(svc, mats, log)
     log(f"set-up: compile {compiles.seconds:.3f}s, {compiles.lowered} "
         f"programs lowered, {compiles.cache_hits} persistent-cache hits")
@@ -317,7 +350,9 @@ def run_cell(workload: str, cell: dict, config: dict, traffic: dict, *,
     import jax
 
     clock = time.perf_counter
-    c = set_up(config, traffic, seed=seed, rows=rows, clock=clock, log=log)
+    chips = int(cell["chips"])
+    c = set_up(config, traffic, chips=chips, seed=seed, rows=rows,
+               clock=clock, log=log)
     svc, pools, mats, sizes = c.svc, c.pools, c.mats, c.sizes
     compiles, devices = c.compiles, c.devices
     dev = devices[0]
@@ -359,7 +394,7 @@ def run_cell(workload: str, cell: dict, config: dict, traffic: dict, *,
     run = Run(loop=traffic["loop"], setup_s=setup_s, window_s=t1 - t0,
               sent=sent, peak_bytes=peak_at.bytes,
               window_compiles=window_compiles,
-              device_kind=dev.device_kind, spans=spans)
+              device_kind=dev.device_kind, chips=chips, spans=spans)
     if traced:
         path = next(Path(trace_dir.name).rglob("*.xplane.pb"))
         run.trace = tracing.summarize(tracing.load(str(path)))

@@ -14,7 +14,9 @@ for, the run exits non-zero and prints no result.
     JAX_PLATFORMS=cpu python3 bench/run.py --workload <cell> --rehearse 4096 ...
 
 rehearses a cell end to end on the CPU with operands of 4096 rows, and
-then fails the platform check all the same.
+then fails the platform check all the same.  A cell of several chips is
+rehearsed on as many virtual CPU devices, set before JAX starts:
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
 import time
 
@@ -67,10 +69,13 @@ def main(argv=None) -> int:
     if not on_chip and not args.rehearse:
         return fail(f"cell {args.workload} needs {cell['chips']} TPU chip(s);"
                     f" JAX found {len(devices)} {dev.platform!r} device(s)")
-    result = harness.run_cell(
-        args.workload, cell, config, traffic, seed=args.seed,
-        seconds=args.seconds, traced=bool(args.trace), t_start=T_START,
-        bench=bench, rows=args.rehearse or None)
+    try:
+        result = harness.run_cell(
+            args.workload, cell, config, traffic, seed=args.seed,
+            seconds=args.seconds, traced=bool(args.trace), t_start=T_START,
+            bench=bench, rows=args.rehearse or None)
+    except harness.TooFewDevices as e:
+        return fail(f"cell {args.workload}: {e}")
     if args.rehearse or not on_chip:
         print(f"rehearsal result: {json.dumps(result)}", file=sys.stderr)
         return fail(f"rehearsal on {dev.platform!r}: not a measurement")

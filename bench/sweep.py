@@ -52,7 +52,8 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
     import harness
     from repro import compile_cache
-    _, config, traffic = harness.cell_files(harness.benchmark(), args.workload)
+    cell, config, traffic = harness.cell_files(harness.benchmark(),
+                                               args.workload)
     compile_cache.configure()
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -61,9 +62,9 @@ def main(argv=None) -> int:
         print("FAIL: no TPU", file=sys.stderr)
         return 1
     clock = time.perf_counter
-    c = harness.set_up(config, traffic, seed=args.seed,
-                       rows=args.rehearse or None, clock=clock,
-                       log=harness._stderr)
+    c = harness.set_up(config, traffic, chips=int(cell["chips"]),
+                       seed=args.seed, rows=args.rehearse or None,
+                       clock=clock, log=harness._stderr)
     for i, rate in enumerate(args.rates):
         sent, t0, t1 = harness.open_window(
             c.svc, c.mats, dict(traffic, rate_per_s=rate), args.seconds,
